@@ -21,6 +21,7 @@ import sys
 from pathlib import Path
 
 from repro.core import registry
+from repro.launch.cache import place_compile_cache
 
 from . import baseline as bl
 from . import runner
@@ -209,6 +210,7 @@ def main(argv=None) -> int:
     p.set_defaults(fn=_cmd_trend)
 
     args = ap.parse_args(argv)
+    place_compile_cache()
     try:
         return args.fn(args)
     except (SchemaError, FileNotFoundError) as e:

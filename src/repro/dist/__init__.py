@@ -5,7 +5,6 @@
 - ``compress``  int8 gradient compression for cross-pod links
 - ``pipeline``  GPipe microbatch pipelining over a mesh axis
 """
-from . import _compat  # noqa: F401  (installs jax.shard_map on old jax)
 from .compress import dequantize_int8, psum_compressed, quantize_int8
 from .pipeline import gpipe_apply
 from .sharding import (

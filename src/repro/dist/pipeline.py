@@ -10,13 +10,9 @@ differentiable (scan + ppermute + where), which is what GPipe training needs.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ._compat import shard_map
 
 
 def gpipe_apply(stage_fn, params, x: jax.Array, mesh: Mesh, axis: str = "pod"):
@@ -63,19 +59,13 @@ def gpipe_apply(stage_fn, params, x: jax.Array, mesh: Mesh, axis: str = "pod"):
         y = jnp.where(sidx == last, y, jnp.zeros_like(y))
         return jax.lax.psum(y, axis)
 
-    # replication checking was renamed check_rep -> check_vma when shard_map
-    # was promoted out of jax.experimental; disable under either name (the
-    # masked-psum output pattern predates the checker's where/psum support)
-    check_kw = (
-        "check_rep"
-        if "check_rep" in inspect.signature(shard_map).parameters
-        else "check_vma"
-    )
-    fn = shard_map(
+    # the masked-psum output pattern is replicated by construction, which
+    # the varying-manual-axes checker cannot prove through the where
+    fn = jax.shard_map(
         pipeline,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), params), P()),
         out_specs=P(),
-        **{check_kw: False},
+        check_vma=False,
     )
     return fn(params, x)

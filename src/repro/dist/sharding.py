@@ -29,8 +29,6 @@ from math import prod
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import _compat  # noqa: F401  (installs jax.shard_map on old jax)
-
 _ctx = threading.local()
 
 

@@ -1,11 +1,12 @@
 """MXU-tiled matmul kernel — the §4.4 arithmetic-throughput probe and the
 block-shape autotuning target.
 
-Grid (M/bm, N/bn, K/bk), K innermost, fp32 accumulation in a VMEM scratch
-(the MXU-native pattern).  Block dims should be multiples of 128 to align
-with the 128x128 systolic array (cf. the paper's finding that >=128
-threads/block are required to fill a Turing SM — the TPU analogue is
-128-aligned MXU tiles).
+Grid (M/bm, N/bn, K/bk), K innermost, accumulation in a VMEM scratch (the
+MXU-native pattern): fp32 for float inputs, int32 for integer inputs (Mosaic
+refuses a float accumulator over an integer lhs).  Block dims should be
+multiples of 128 to align with the 128x128 systolic array (cf. the paper's
+finding that >=128 threads/block are required to fill a Turing SM — the TPU
+analogue is 128-aligned MXU tiles).
 """
 from __future__ import annotations
 
@@ -64,12 +65,18 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=acc_ref.dtype
     )
 
     @pl.when(k == nk - 1)
     def _():
-        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+        acc = acc_ref[...]
+        if jnp.issubdtype(o_ref.dtype, jnp.integer):
+            # saturate like the oracle's float->int convert; an int32
+            # accumulator would otherwise wrap on the narrowing cast
+            info = jnp.iinfo(o_ref.dtype)
+            acc = jnp.clip(acc, info.min, info.max)
+        o_ref[...] = acc.astype(o_ref.dtype)
 
 
 def matmul_pallas(
@@ -89,6 +96,7 @@ def matmul_pallas(
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, ((m, k, n), (bm, bk, bn))
     out_dtype = out_dtype or a.dtype
     grid = (m // bm, n // bn, k // bk)
+    acc_dtype = jnp.int32 if jnp.issubdtype(a.dtype, jnp.integer) else jnp.float32
     return pl.pallas_call(
         _matmul_kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
@@ -98,6 +106,6 @@ def matmul_pallas(
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=interpret,
     )(a, b)

@@ -7,13 +7,15 @@ plays the same side-effect role as the paper's ``dsink``).
 
 Block shape is the probe variable: footprint-per-step = block bytes, so
 sweeping block shape vs. array footprint maps the memory-hierarchy transfer
-efficiency exactly like the paper's working-set sweeps.
+efficiency exactly like the paper's working-set sweeps.  The checksum is an
+SMEM scalar: Mosaic cannot store a scalar to VMEM.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _copy_kernel(x_ref, o_ref):
@@ -42,9 +44,20 @@ def _reduce_kernel(x_ref, o_ref):
 
     @pl.when(jnp.logical_and(i == 0, j == 0))
     def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[0, 0] = 0.0
 
     o_ref[0, 0] += jnp.sum(x_ref[...].astype(jnp.float32))
+
+
+def _reduce_call(x: jax.Array, block: tuple, grid: tuple, interpret: bool) -> jax.Array:
+    return pl.pallas_call(
+        _reduce_kernel,
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        grid=grid,
+        in_specs=[pl.BlockSpec(block, lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        interpret=interpret,
+    )(x)
 
 
 def stream_reduce(
@@ -53,42 +66,22 @@ def stream_reduce(
     """Read-bandwidth probe: returns the (1,1) fp32 checksum."""
     r, c = x.shape
     assert r % block_rows == 0 and c % block_cols == 0
-    grid = (r // block_rows, c // block_cols)
-    return pl.pallas_call(
-        _reduce_kernel,
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        interpret=interpret,
-    )(x)
-
-
-def _strided_reduce_kernel(x_ref, o_ref, *, stride: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    # touch one lane-row out of every `stride` sublane-rows: sparse-access
-    # pattern probing load granularity (paper Tab 3.1 "load granularity")
-    o_ref[0, 0] += jnp.sum(x_ref[::stride, :].astype(jnp.float32))
+    return _reduce_call(
+        x, (block_rows, block_cols), (r // block_rows, c // block_cols), interpret
+    )
 
 
 def strided_reduce(
     x: jax.Array, *, stride: int, block_rows: int = 64, interpret: bool = True
 ) -> jax.Array:
-    r, c = x.shape
-    assert r % block_rows == 0
-    grid = (r // block_rows,)
-    from functools import partial
+    """Checksum of one row out of every ``stride`` (paper Tab 3.1 "load
+    granularity"): sparse access probing the transfer unit.
 
-    return pl.pallas_call(
-        partial(_strided_reduce_kernel, stride=stride),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, c), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        interpret=interpret,
-    )(x)
+    Row ``t * stride`` of ``x`` is the first ``c`` columns of row ``t`` of the
+    (r/stride, stride*c) view, so each block fetches only the rows it sums
+    (Mosaic has no strided VMEM load over a last dim other than 128).
+    """
+    r, c = x.shape
+    assert r % block_rows == 0 and block_rows % stride == 0
+    view = x.reshape(r // stride, stride * c)
+    return _reduce_call(view, (block_rows // stride, c), (r // block_rows, 1), interpret)

@@ -6,9 +6,10 @@ array.  On TPU the interesting transition is VMEM-resident vs. HBM-streamed;
 on the CPU host (measure mode) the same kernel traces out L1/L2/L3/DRAM —
 which is how we validate the methodology end-to-end (core/dissect.py).
 
-The index lives in SMEM-like scalar space (a (1,1) block) — the TPU analogue
-of the paper's §3.5.2 "uniform datapath" observation: index math stays off
-the vector path.
+The walked array stays VMEM-resident and the final index is written to an
+SMEM scalar (Mosaic cannot store a scalar to VMEM) — the TPU analogue of the
+paper's §3.5.2 "uniform datapath" observation: index math stays off the
+vector path.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pchase_kernel(perm_ref, o_ref, *, steps: int):
@@ -36,6 +38,6 @@ def pchase_pallas(perm: jax.Array, steps: int, *, interpret: bool = True) -> jax
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         grid=(1,),
         in_specs=[pl.BlockSpec((n, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         interpret=interpret,
     )(perm2)

@@ -124,9 +124,7 @@ def cost_reference(cfg: ModelConfig, shape: ShapeConfig) -> dict:
         lowered = jax.jit(model.decode_step).lower(
             params_sh, specs["cache"], specs["tokens"], specs["pos"]
         )
-    from repro.perfmodel.costs import _as_cost_dict
-
-    ca = _as_cost_dict(lowered.cost_analysis())
+    ca = lowered.cost_analysis() or {}
     return {
         "global_flops": float(ca.get("flops", 0.0)),
         "global_bytes_prefusion": float(ca.get("bytes accessed", 0.0)),

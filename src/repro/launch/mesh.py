@@ -11,17 +11,10 @@ device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types (Auto keeps the pre-0.5 behavior)
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types parameter
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -30,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import place_compile_cache
 from repro.models import build_model
 from repro.serve import (
     SCHEDULERS,
@@ -93,6 +94,7 @@ def main(argv=None):
     ap.add_argument("--chaos-faults", type=int, default=4,
                     help="number of scheduled faults (with --chaos)")
     args = ap.parse_args(argv)
+    place_compile_cache()
     try:  # fail fast on a bad router name; the error lists registered names
         make_router(args.router)
     except ValueError as e:
@@ -103,7 +105,7 @@ def main(argv=None):
         cfg = cfg.reduced()
 
     model = build_model(cfg)
-    params = model.init(jax.random.key(args.seed))
+    params = jax.jit(model.init)(jax.random.key(args.seed))
     engine_cfg = EngineConfig(
         n_slots=args.slots,
         max_len=args.max_len,

@@ -41,16 +41,8 @@ class CompiledCosts:
         }
 
 
-def _as_cost_dict(ca: Any) -> dict:
-    """cost_analysis() returns a dict on newer jax, a per-device list of
-    dicts on older versions — normalize to the (first-device) dict."""
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 def extract_costs(compiled: Any) -> CompiledCosts:
-    ca = _as_cost_dict(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     return CompiledCosts(
         flops_per_device=float(ca.get("flops", 0.0)),

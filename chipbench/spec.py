@@ -1,0 +1,80 @@
+"""What a cell is, read from ``BENCHMARK.json`` and the files it names.
+
+Nothing here knows a cell, a configuration, a mix or a metric by name:
+
+- a configuration is the file its ``configs`` entry names, with every size
+  and the engine's settings (``EngineConfig``: lanes, ``max_len``, chunk,
+  KV layout);
+- a traffic mix is ``chipbench/traffic/<traffic>.json``;
+- a metric is ``chipbench/metrics/<name>.py``, whose ``read(run)`` returns
+  the number or None when the run holds nothing to read.
+
+A later change adds a cell, a configuration, a mix or a metric by adding
+files and entries, and edits no file that is here.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+from dataclasses import dataclass
+from pathlib import Path
+from typing import Callable
+
+
+@dataclass(frozen=True)
+class Cell:
+    name: str
+    chips: int
+    config: dict
+    traffic: dict
+    end_to_end: tuple  # BENCHMARK.json metric entries this cell reports
+    per_layer: tuple
+
+
+def _applies(entry: dict, cell: str, reported=None) -> bool:
+    """A metric with ``workloads`` is read in those cells; a per-layer metric
+    without it in every cell that reports the end-to-end metric it moves."""
+    if "workloads" in entry:
+        return cell in entry["workloads"]
+    return reported is None or entry["moves"] in reported
+
+
+def load_cell(root: Path, name: str) -> Cell:
+    """The cell ``name`` of ``root/BENCHMARK.json``, with its files read."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if name not in cells:
+        raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
+    w = cells[name]
+    conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    e2e = tuple(m for m in bench["end_to_end"] if _applies(m, name))
+    names = {m["name"] for m in e2e}
+    per_layer = tuple(m for m in bench["per_layer"] if _applies(m, name, names))
+    return Cell(
+        name=name,
+        chips=int(w["chips"]),
+        config=json.loads((root / conf["file"]).read_text()),
+        traffic=json.loads((root / "chipbench" / "traffic" / f"{w['traffic']}.json").read_text()),
+        end_to_end=e2e,
+        per_layer=per_layer,
+    )
+
+
+def reader(root: Path, metric: str) -> Callable:
+    """``read`` of ``chipbench/metrics/<metric>.py``."""
+    path = root / "chipbench" / "metrics" / f"{metric}.py"
+    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{metric}", path)
+    if spec is None or not path.is_file():
+        raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def peaks(root: Path, device_kind: str) -> dict:
+    """The chip's published peaks; an unknown kind is an error, not a default."""
+    table = json.loads((root / "chipbench" / "peaks.json").read_text())
+    if device_kind not in table:
+        raise KeyError(f"device kind {device_kind!r} not in chipbench/peaks.json "
+                       f"(known: {sorted(table)})")
+    return table[device_kind]

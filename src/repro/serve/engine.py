@@ -67,6 +67,7 @@ from repro.kernels import guard as kguard
 from repro.kernels.api import BACKENDS, current_policy, kernel_policy
 from repro.models.api import ModelApi
 
+from . import tracing
 from .metrics import EngineMetrics
 from .paging import PageAllocator, PagePoolExhausted, SharedPrefix
 from .sampler import greedy
@@ -381,6 +382,7 @@ class ServeEngine:
             self._chunk = self._jit_scoped(model.decode_chunk)
             self.pos = jnp.zeros((config.n_slots,), jnp.int32)
         self.metrics = EngineMetrics(config.n_slots, n_pages=self.n_pages)
+        tracing.install_gc_hook()
 
     # ------------------------------------------------------------------
     def _place_cache(self, cache, shardings_fn: Optional[Callable]):
@@ -956,48 +958,65 @@ class ServeEngine:
         t0 = time.perf_counter()
         n_slots, chunk = self.cfg.n_slots, self.cfg.prefill_chunk
         spans = {lane: len(feed) - start for lane, _, feed, start in assignments}
-        longest = max(spans.values())
-        n_chunks = -(-longest // chunk)
-        toks = np.zeros((n_slots, n_chunks * chunk), np.int32)
-        poss = np.full((n_slots, n_chunks * chunk), self._pad_pos, np.int32)
-        for lane, _, feed, start in assignments:
-            n = len(feed) - start
-            toks[lane, :n] = feed[start:]
-            poss[lane, :n] = np.arange(start, len(feed), dtype=np.int32)
-        bt_args = (jnp.asarray(self._bt),) if self.paged else ()
-        for c in range(n_chunks):
-            sl = slice(c * chunk, (c + 1) * chunk)
-            logits, self.cache = self._call_compiled(
-                "chunk", self.params, self.cache, *bt_args,
-                jnp.asarray(toks[:, sl]), jnp.asarray(poss[:, sl]),
-            )
-            ending = [
-                (lane, s, feed) for lane, s, feed, start in assignments
-                if c * chunk < len(feed) - start <= (c + 1) * chunk
-            ]
-            for lane, s, feed in ending:
-                row = logits[lane, spans[lane] - 1 - c * chunk]
-                if self.cfg.nan_guard and not bool(jnp.all(jnp.isfinite(row))):
-                    if self._nan_attr_tick != self.tick:
-                        self._nan_attr_tick = self.tick
-                        self._guard_attribute(
-                            RuntimeError(f"non-finite prefill logits on lane {lane}")
-                        )
-                    self._quarantine_lane(lane, s)  # retry the session whole
-                    continue
-                tok = int(self.cfg.sampler(row))
-                s.status = ACTIVE
-                self.last_token = self.last_token.at[lane].set(tok)
-                self.pos = self.pos.at[lane].set(len(feed))
-                self._lane_pos[lane] = len(feed)
-                s._record_token(tok)  # TTFT stamps here (first admission only)
-                reason = self._finish_reason(lane, s, tok)
-                if reason:
-                    self._finalize(lane, s, reason)
+        n_fed = sum(spans.values())
+        n_chunks = -(-max(spans.values()) // chunk)
+        with tracing.span(tracing.PREFILL, tokens=n_fed,
+                          rids=tracing.rids(s for _, s, _, _ in assignments)):
+            toks = np.zeros((n_slots, n_chunks * chunk), np.int32)
+            poss = np.full((n_slots, n_chunks * chunk), self._pad_pos, np.int32)
+            for lane, _, feed, start in assignments:
+                n = len(feed) - start
+                toks[lane, :n] = feed[start:]
+                poss[lane, :n] = np.arange(start, len(feed), dtype=np.int32)
+            bt_args = self._block_table_args()
+            for c in range(n_chunks):
+                with tracing.span(tracing.PREFILL_CHUNK):
+                    sl = slice(c * chunk, (c + 1) * chunk)
+                    logits, self.cache = self._call_compiled(
+                        "chunk", self.params, self.cache, *bt_args,
+                        jnp.asarray(toks[:, sl]), jnp.asarray(poss[:, sl]),
+                    )
+                with tracing.span(tracing.PREFILL_SAMPLE):
+                    self._sample_prefilled(assignments, spans, logits, c)
         self.metrics.record_prefill(
             (time.perf_counter() - t0) * self.step_time_scale,
-            sum(spans.values()), len(assignments),
+            n_fed, n_chunks * n_slots * chunk,
         )
+
+    def _sample_prefilled(self, assignments: list, spans: dict, logits, c: int) -> None:
+        """First token of every lane whose feed ends in chunk ``c``."""
+        chunk = self.cfg.prefill_chunk
+        ending = [
+            (lane, s, feed) for lane, s, feed, start in assignments
+            if c * chunk < len(feed) - start <= (c + 1) * chunk
+        ]
+        for lane, s, feed in ending:
+            row = logits[lane, spans[lane] - 1 - c * chunk]
+            if self.cfg.nan_guard and not bool(jnp.all(jnp.isfinite(row))):
+                if self._nan_attr_tick != self.tick:
+                    self._nan_attr_tick = self.tick
+                    self._guard_attribute(
+                        RuntimeError(f"non-finite prefill logits on lane {lane}")
+                    )
+                self._quarantine_lane(lane, s)  # retry the session whole
+                continue
+            tok = int(self.cfg.sampler(row))
+            s.status = ACTIVE
+            self.last_token = self.last_token.at[lane].set(tok)
+            self.pos = self.pos.at[lane].set(len(feed))
+            self._lane_pos[lane] = len(feed)
+            s._record_token(tok)  # TTFT stamps here (first admission only)
+            reason = self._finish_reason(lane, s, tok)
+            if reason:
+                self._finalize(lane, s, reason)
+
+    def _block_table_args(self) -> tuple:
+        """The paged steps' block-table argument, copied to the device (no
+        argument for dense KV)."""
+        if not self.paged:
+            return ()
+        with tracing.span(tracing.UPLOAD):
+            return (jnp.asarray(self._bt),)
 
     # ------------------------------------------------------------------
     def step(self) -> None:
@@ -1015,78 +1034,85 @@ class ServeEngine:
             )
         t_step0 = time.perf_counter()
         self.tick += 1
-        if self._op_quarantine:  # quarantined kernel ops due for a re-probe
-            self._heal_ops()
-        if self._quarantined:  # lanes whose bench time has elapsed come back
-            self._quarantined = {
-                lane: t for lane, t in self._quarantined.items() if t > self.tick
-            }
-        self._release_cancelled()
-        self._expire_deadlines()
-        admitted = self._admit()
-        if admitted:
-            self._prefill(admitted)
-        if self.paged:
-            for lane in range(self.cfg.n_slots):
-                if self.slots[lane] is not None:
-                    self._grow_lane(lane)
-        active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
+        with tracing.span(tracing.TICK, tick=self.tick):
+            if self._op_quarantine:  # quarantined kernel ops due for a re-probe
+                self._heal_ops()
+            if self._quarantined:  # lanes whose bench time has elapsed come back
+                self._quarantined = {
+                    lane: t for lane, t in self._quarantined.items() if t > self.tick
+                }
+            with tracing.span(tracing.ADMIT):
+                self._release_cancelled()
+                self._expire_deadlines()
+                admitted = self._admit()
+            if admitted:
+                self._prefill(admitted)
+            if self.paged:
+                with tracing.span(tracing.GROW):
+                    for lane in range(self.cfg.n_slots):
+                        if self.slots[lane] is not None:
+                            self._grow_lane(lane)
+            active = [i for i, s in enumerate(self.slots) if s is not None]
+            if active:
+                self._decode_tick(active)
             self.last_step_s = (time.perf_counter() - t_step0) * self.step_time_scale
             self.last_step_recompiled, self._recompiled = self._recompiled, False
-            return
+
+    def _decode_tick(self, active: list) -> None:
+        """One decode step over every lane, then the tokens' bookkeeping."""
         t0 = time.perf_counter()
-        bt_args = (jnp.asarray(self._bt),) if self.paged else ()
-        logits, self.cache = self._call_compiled(
-            "decode", self.params, self.cache, *bt_args, self.last_token, self.pos
-        )
-        if self._inject_nan_lanes:  # fault surface: poison the real logits
-            for lane in sorted(self._inject_nan_lanes):
-                if 0 <= lane < self.cfg.n_slots:
-                    logits = logits.at[lane].set(jnp.nan)
-        bad = []
-        if self.cfg.nan_guard:
-            finite = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
-            bad = [i for i in active if not finite[i]]
-        if bad and self._nan_attr_tick != self.tick:
-            # a kernel op emitting non-finite values shows up in its probe:
-            # quarantine it per-op (the lanes still retry below either way)
-            self._nan_attr_tick = self.tick
-            self._guard_attribute(
-                RuntimeError(f"non-finite decode logits on lane(s) {bad}")
+        bt_args = self._block_table_args()
+        with tracing.span(tracing.DECODE, lanes=len(active)):
+            logits, self.cache = self._call_compiled(
+                "decode", self.params, self.cache, *bt_args, self.last_token, self.pos
             )
-        next_tok = self.cfg.sampler(logits)
-        jax.block_until_ready(next_tok)
+            if self._inject_nan_lanes:  # fault surface: poison the real logits
+                for lane in sorted(self._inject_nan_lanes):
+                    if 0 <= lane < self.cfg.n_slots:
+                        logits = logits.at[lane].set(jnp.nan)
+        with tracing.span(tracing.DECODE_SYNC):
+            bad = []
+            if self.cfg.nan_guard:
+                finite = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
+                bad = [i for i in active if not finite[i]]
+            if bad and self._nan_attr_tick != self.tick:
+                # a kernel op emitting non-finite values shows up in its probe:
+                # quarantine it per-op (the lanes still retry below either way)
+                self._nan_attr_tick = self.tick
+                self._guard_attribute(
+                    RuntimeError(f"non-finite decode logits on lane(s) {bad}")
+                )
+            next_tok = self.cfg.sampler(logits)
+            jax.block_until_ready(next_tok)
         t_decode = time.perf_counter() - t0
-        for i in bad:  # quarantine before pos advances: the lane pads out
-            self._quarantine_lane(i, self.slots[i])
-        ok = [i for i in active if i not in bad]
-        self.last_token = next_tok
-        # pad lanes must stay at the sentinel (a pad-lane write would land in
-        # pool pages someone else owns); surviving active lanes advance by one
-        if self.paged:
-            adv = jnp.zeros((self.cfg.n_slots,), jnp.int32)
+        with tracing.span(tracing.BOOKKEEP):
+            for i in bad:  # quarantine before pos advances: the lane pads out
+                self._quarantine_lane(i, self.slots[i])
+            ok = [i for i in active if i not in bad]
+            self.last_token = next_tok
+            # pad lanes must stay at the sentinel (a pad-lane write would land
+            # in pool pages someone else owns); surviving active lanes advance
+            if self.paged:
+                adv = jnp.zeros((self.cfg.n_slots,), jnp.int32)
+                for i in ok:
+                    adv = adv.at[i].set(1)
+                self.pos = self.pos + adv
+            else:
+                self.pos = self.pos + 1
+            toks = np.asarray(next_tok)
             for i in ok:
-                adv = adv.at[i].set(1)
-            self.pos = self.pos + adv
-        else:
-            self.pos = self.pos + 1
-        toks = np.asarray(next_tok)
-        for i in ok:
-            s = self.slots[i]
-            self._lane_pos[i] += 1
-            s._record_token(int(toks[i]))
-            reason = self._finish_reason(i, s, int(toks[i]))
-            if reason:
-                self._finalize(i, s, reason)
-        scale = self.step_time_scale
-        self.metrics.record_tick(
-            (time.perf_counter() - t0) * scale, t_decode * scale, len(active)
-        )
-        if self.paged:
-            self.metrics.record_pages(self.allocator.used)
-        self.last_step_s = (time.perf_counter() - t_step0) * scale
-        self.last_step_recompiled, self._recompiled = self._recompiled, False
+                s = self.slots[i]
+                self._lane_pos[i] += 1
+                s._record_token(int(toks[i]))
+                reason = self._finish_reason(i, s, int(toks[i]))
+                if reason:
+                    self._finalize(i, s, reason)
+            scale = self.step_time_scale
+            self.metrics.record_tick(
+                (time.perf_counter() - t0) * scale, t_decode * scale, len(active)
+            )
+            if self.paged:
+                self.metrics.record_pages(self.allocator.used)
 
     # ------------------------------------------------------------------
     def has_work(self) -> bool:

@@ -41,7 +41,7 @@ class EngineMetrics:
         self.occupancy: list = []  # active slots at each decode tick
         self.prefill_s: list = []  # per prefill flush (all chunks)
         self.prefill_tokens = 0  # prompt tokens prefilled
-        self.prefill_requests = 0
+        self.prefill_slots = 0  # lane-positions the prefill calls computed, pads included
         self.ttft_s: list = []  # per finished request
         self.token_latency_s: list = []  # inter-token gaps, pooled
         self.generated_tokens = 0
@@ -71,10 +71,12 @@ class EngineMetrics:
         self.decode_s.append(decode_seconds)
         self.occupancy.append(n_active)
 
-    def record_prefill(self, seconds: float, n_tokens: int, n_requests: int) -> None:
+    def record_prefill(self, seconds: float, n_tokens: int, n_slots: int) -> None:
+        """One flush: ``n_tokens`` real positions fed, ``n_slots`` computed
+        (lanes x ``prefill_chunk`` per chunk call)."""
         self.prefill_s.append(seconds)
         self.prefill_tokens += n_tokens
-        self.prefill_requests += n_requests
+        self.prefill_slots += n_slots
 
     def record_pages(self, pages_in_use: int) -> None:
         self.pages_used.append(pages_in_use)

@@ -2,9 +2,10 @@
 
 One compiled fixed-shape decode step serves all slots every tick; admission
 between ticks is delegated to a swappable :class:`~repro.serve.scheduler.
-Scheduler`; prompt ingestion runs as *chunked batched prefill* — one compiled
-``ModelApi.decode_chunk`` call per chunk, shared across every slot admitted
-that tick.  Every tick is measured into :class:`~repro.serve.metrics.
+Scheduler`; prompt ingestion runs as *chunked batched prefill* — compiled
+``ModelApi.decode_chunk`` calls of ``n_slots * prefill_chunk`` positions, laid
+either across every slot admitted that tick or along one lane at a time,
+whichever takes fewer calls.  Every tick is measured into :class:`~repro.serve.metrics.
 EngineMetrics` and the compiled steps trace under the :class:`EngineConfig`'s
 kernel-policy backend, so one engine definition runs the pallas / interpret /
 xla paths side by side.
@@ -56,6 +57,7 @@ from __future__ import annotations
 import time
 import warnings
 from dataclasses import dataclass
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -90,6 +92,22 @@ from .session import (
 #: carry per-lane conv/ssm state that cannot yet advance independently inside
 #: a shared batch — see the ROADMAP per-lane state isolation item.
 SERVABLE_FAMILIES = ("dense", "moe", "vlm")
+
+
+@jax.jit
+def _read_lane_kv(cache, lane):
+    """One lane of a dense cache, ``(L, 1, Smax, K, hd)`` per leaf.  ``lane``
+    is traced, so every lane shares one executable."""
+    return jax.tree.map(lambda a: jax.lax.dynamic_slice_in_dim(a, lane, 1, axis=1), cache)
+
+
+@partial(jax.jit, donate_argnums=0)
+def _write_lane_kv(cache, kv, lane):
+    """``cache`` with lane ``lane`` replaced by ``kv`` (in place: the cache
+    is donated)."""
+    return jax.tree.map(
+        lambda a, b: jax.lax.dynamic_update_slice_in_dim(a, b, lane, axis=1), cache, kv)
+
 
 
 class UnsupportedFamilyError(NotImplementedError):
@@ -156,9 +174,10 @@ class EngineConfig:
 
     - ``n_slots`` — lanes in the compiled batch (the decode step's B).
     - ``max_len`` — logical cap on prompt+generated length per request.
-    - ``prefill_chunk`` — tokens per compiled prefill step (a smaller chunk
-      interleaves admission with decode sooner; a larger one amortizes
-      dispatch).
+    - ``prefill_chunk`` — tokens per lane of a compiled all-lanes prefill
+      step; a single-lane step feeds ``n_slots * prefill_chunk`` tokens of
+      one lane (a smaller chunk interleaves admission with decode sooner; a
+      larger one amortizes dispatch).
     - ``page_size`` — KV slots per page.  ``None`` selects the dense layout;
       set it to enable the paged layout described in the module docstring.
     - ``n_pages`` — page-pool size.  Defaults to
@@ -364,7 +383,7 @@ class ServeEngine:
             self.cache = self._place_cache(model.init_paged_cache(self.n_pages, ps),
                                            model.paged_cache_shardings)
             self._decode = self._jit_scoped(model.decode_step_paged)
-            self._chunk = self._jit_scoped(model.decode_chunk_paged)
+            self._chunk = self._chunk_jit = self._jit_scoped(model.decode_chunk_paged)
             self._copy_page_fn = jax.jit(
                 lambda cache, s, d: jax.tree.map(
                     lambda a: a.at[:, d].set(a[:, s]), cache
@@ -379,8 +398,9 @@ class ServeEngine:
                 model.cache_shardings,
             )
             self._decode = self._jit_scoped(model.decode_step)
-            self._chunk = self._jit_scoped(model.decode_chunk)
+            self._chunk = self._chunk_jit = self._jit_scoped(model.decode_chunk)
             self.pos = jnp.zeros((config.n_slots,), jnp.int32)
+        self._prefill_warm = False  # set once a flush compiled every prefill shape
         self.metrics = EngineMetrics(config.n_slots, n_pages=self.n_pages)
         tracing.install_gc_hook()
 
@@ -441,17 +461,23 @@ class ServeEngine:
         return bool(self._op_quarantine)
 
     def _rejit_steps(self, backend: Optional[str] = None) -> None:
-        """Re-jit both compiled steps (per-op quarantine / revival / whole-
+        """(Re-)jit both compiled steps (per-op quarantine / revival / whole-
         engine degradation all change what a fresh trace dispatches to); the
-        lazy shadow twins rebuild on next use."""
+        lazy shadow twins rebuild on next use, and the next flush compiles
+        every prefill shape again (:meth:`_warm_prefill`).  ``_chunk_jit``
+        keeps the jitted prefill step itself: flushes call ``_chunk``, which
+        callers may wrap to observe each call."""
         self._recompiled = True
         if self.paged:
             self._decode = self._jit_scoped(self.model.decode_step_paged, backend=backend)
-            self._chunk = self._jit_scoped(self.model.decode_chunk_paged, backend=backend)
+            self._chunk = self._chunk_jit = self._jit_scoped(
+                self.model.decode_chunk_paged, backend=backend)
         else:
             self._decode = self._jit_scoped(self.model.decode_step, backend=backend)
-            self._chunk = self._jit_scoped(self.model.decode_chunk, backend=backend)
+            self._chunk = self._chunk_jit = self._jit_scoped(
+                self.model.decode_chunk, backend=backend)
         self._shadow_decode = self._shadow_chunk = None
+        self._prefill_warm = False
 
     def _degrade(self, err: Exception) -> None:
         """Whole-engine fallback: re-jit decode/prefill on the ``xla``
@@ -711,22 +737,20 @@ class ServeEngine:
                 f"one worst-case lane ({self.allocator.free_pages} free)"
             )
         pages = self.allocator.alloc(n_t)
-        # Prefill the prefix KV through a temporary block-table view: row 0
-        # maps to the prefix pages, every other row is pad (writes nothing,
-        # reads garbage logits nobody samples) — live lanes are untouched
-        # because writes target pool positions, not lanes.
-        ps, chunk = self.cfg.page_size, self.cfg.prefill_chunk
-        bt = self._bt.copy()
-        bt[0, :] = 0
+        # Prefill the prefix KV in single-lane calls through a one-row
+        # block table that maps to the prefix pages — live lanes are
+        # untouched because writes target pool positions, not lanes.
+        width = self.cfg.n_slots * self.cfg.prefill_chunk
+        bt = np.zeros((1, self._table_width), np.int32)
         bt[0, :n_t] = pages
-        n_chunks = -(-len(tokens) // chunk)
-        toks = np.zeros((self.cfg.n_slots, n_chunks * chunk), np.int32)
-        poss = np.full((self.cfg.n_slots, n_chunks * chunk), self._pad_pos, np.int32)
+        n_calls = -(-len(tokens) // width)
+        toks = np.zeros((1, n_calls * width), np.int32)
+        poss = np.full((1, n_calls * width), self._pad_pos, np.int32)
         toks[0, : len(tokens)] = tokens
         poss[0, : len(tokens)] = np.arange(len(tokens), dtype=np.int32)
         bt_dev = jnp.asarray(bt)
-        for c in range(n_chunks):
-            sl = slice(c * chunk, (c + 1) * chunk)
+        for c in range(n_calls):
+            sl = slice(c * width, (c + 1) * width)
             _, self.cache = self._chunk(
                 self.params, self.cache, bt_dev,
                 jnp.asarray(toks[:, sl]), jnp.asarray(poss[:, sl]),
@@ -945,10 +969,19 @@ class ServeEngine:
 
     # ------------------------------------------------------------------
     def _prefill(self, assignments: list) -> None:
-        """Chunked batched prefill: every admitted prompt advances through
-        the same compiled ``decode_chunk`` call, ``prefill_chunk`` tokens per
-        step.  Lanes not being prefilled carry the pad position sentinel,
-        which writes nothing — mid-generation neighbours are untouched.
+        """Chunked batched prefill of one flush, in whichever of two compiled
+        shapes takes fewer calls (a tie goes to single-lane):
+
+        - all lanes: ``n_slots`` x ``prefill_chunk`` positions a call, each
+          admitted lane in its own row; ``ceil(longest span / prefill_chunk)``
+          calls.
+        - single lane: 1 x ``n_slots * prefill_chunk`` positions a call, lane
+          after lane; ``sum(ceil(span / (n_slots * prefill_chunk)))`` calls.
+
+        Both shapes compute the same positions a call, so the rule counts
+        calls: one long prompt goes single-lane, a burst of short ones all
+        lanes.  Positions not fed carry the pad position sentinel, which
+        writes nothing — mid-generation neighbours are untouched.
 
         Each assignment is ``(lane, session, feed, start)``: ``feed`` is the
         token stream whose KV the lane must hold (prompt, plus prior output
@@ -956,42 +989,107 @@ class ServeEngine:
         fed — positions below it come from shared prefix pages.
         """
         t0 = time.perf_counter()
-        n_slots, chunk = self.cfg.n_slots, self.cfg.prefill_chunk
-        spans = {lane: len(feed) - start for lane, _, feed, start in assignments}
-        n_fed = sum(spans.values())
-        n_chunks = -(-max(spans.values()) // chunk)
-        with tracing.span(tracing.PREFILL, tokens=n_fed,
+        spans = [len(feed) - start for _, _, feed, start in assignments]
+        single = self._single_lane(spans)
+        with tracing.span(tracing.PREFILL, tokens=sum(spans),
                           rids=tracing.rids(s for _, s, _, _ in assignments)):
-            toks = np.zeros((n_slots, n_chunks * chunk), np.int32)
-            poss = np.full((n_slots, n_chunks * chunk), self._pad_pos, np.int32)
-            for lane, _, feed, start in assignments:
-                n = len(feed) - start
-                toks[lane, :n] = feed[start:]
-                poss[lane, :n] = np.arange(start, len(feed), dtype=np.int32)
-            bt_args = self._block_table_args()
-            for c in range(n_chunks):
-                with tracing.span(tracing.PREFILL_CHUNK):
-                    sl = slice(c * chunk, (c + 1) * chunk)
-                    logits, self.cache = self._call_compiled(
-                        "chunk", self.params, self.cache, *bt_args,
-                        jnp.asarray(toks[:, sl]), jnp.asarray(poss[:, sl]),
-                    )
-                with tracing.span(tracing.PREFILL_SAMPLE):
-                    self._sample_prefilled(assignments, spans, logits, c)
+            self._warm_prefill(single)
+            if single:
+                # highest lane first: lane 0, admitted first, then holds its
+                # session through every call (a wrapper of ``_chunk`` may
+                # read row 0 of a single-lane call as lane 0)
+                order = sorted(assignments, key=lambda a: -a[0])
+                bts = self._block_table_args([a[0] for a in order])
+                n_calls = sum(self._feed([a], bt, lane=a[0]) for a, bt in zip(order, bts))
+            else:
+                n_calls = self._feed(assignments, self._block_table_args())
         self.metrics.record_prefill(
-            (time.perf_counter() - t0) * self.step_time_scale,
-            n_fed, n_chunks * n_slots * chunk,
+            (time.perf_counter() - t0) * self.step_time_scale, sum(spans),
+            n_calls * self.cfg.n_slots * self.cfg.prefill_chunk, n_calls if single else 0,
         )
 
-    def _sample_prefilled(self, assignments: list, spans: dict, logits, c: int) -> None:
-        """First token of every lane whose feed ends in chunk ``c``."""
-        chunk = self.cfg.prefill_chunk
-        ending = [
-            (lane, s, feed) for lane, s, feed, start in assignments
-            if c * chunk < len(feed) - start <= (c + 1) * chunk
-        ]
-        for lane, s, feed in ending:
-            row = logits[lane, spans[lane] - 1 - c * chunk]
+    def _single_lane(self, spans: list) -> bool:
+        """The layout rule: a flush of these fed spans takes single-lane
+        calls when they are fewer than all-lanes calls, or as many."""
+        n_slots, chunk = self.cfg.n_slots, self.cfg.prefill_chunk
+        lane_calls = sum(-(-n // (n_slots * chunk)) for n in spans)
+        return n_slots > 1 and lane_calls <= -(-max(spans) // chunk)  # one slot: one shape
+
+    def _feed(self, group: list, bt_args: tuple, lane: Optional[int] = None) -> int:
+        """The chunk calls that feed ``group``'s assignments, each lane's
+        first token sampled after the call its feed ends in; returns the
+        number of calls.  All-lanes calls with ``lane`` None; else
+        single-lane calls that feed ``lane``, the group's one lane, from row
+        0 — with dense KV, on that lane's KV alone, read out before its first
+        call and written back after its last."""
+        rows = self.cfg.n_slots if lane is None else 1
+        width = self.cfg.n_slots * self.cfg.prefill_chunk // rows
+        n_calls = -(-max(len(feed) - start for _, _, feed, start in group) // width)
+        toks = np.zeros((rows, n_calls * width), np.int32)
+        poss = np.full((rows, n_calls * width), self._pad_pos, np.int32)
+        for i, _, feed, start in group:
+            r = i if lane is None else 0
+            toks[r, : len(feed) - start] = feed[start:]
+            poss[r, : len(feed) - start] = np.arange(start, len(feed), dtype=np.int32)
+        own_kv = lane is not None and not self.paged
+        if own_kv:
+            lane_idx = jnp.asarray(lane, jnp.int32)
+            kv = _read_lane_kv(self.cache, lane_idx)
+        for c in range(n_calls):
+            with tracing.span(tracing.PREFILL_CHUNK, lanes=rows):
+                sl = slice(c * width, (c + 1) * width)
+                logits, out = self._call_compiled(
+                    "chunk", self.params, kv if own_kv else self.cache, *bt_args,
+                    jnp.asarray(toks[:, sl]), jnp.asarray(poss[:, sl]),
+                )
+                if own_kv:
+                    kv = out
+                else:
+                    self.cache = out
+            with tracing.span(tracing.PREFILL_SAMPLE):
+                self._sample_prefilled(group, logits, c, width, lane is not None)
+        if own_kv:
+            self.cache = _write_lane_kv(self.cache, kv, lane_idx)
+        return n_calls
+
+    def _warm_prefill(self, single: bool) -> None:
+        """On the first flush after (re-)jitting, compile the prefill shape
+        this flush does not run — one all-pad call, which writes nothing,
+        and the logits row read that sampling makes — so that no later flush
+        compiles.  Calls the jitted step itself, never a wrapper of
+        ``_chunk``."""
+        if self._prefill_warm:
+            return
+        self._prefill_warm = True
+        n_slots = self.cfg.n_slots
+        if n_slots == 1:  # both layouts are one shape
+            return
+        rows = n_slots if single else 1
+        width = n_slots * self.cfg.prefill_chunk // rows
+        toks = jnp.asarray(np.zeros((rows, width), np.int32))
+        poss = jnp.asarray(np.full((rows, width), self._pad_pos, np.int32))
+        if self.paged:
+            bt = jnp.asarray(self._bt[:rows])
+            logits, self.cache = self._chunk_jit(self.params, self.cache, bt, toks, poss)
+        elif rows == 1:
+            lane = jnp.asarray(0, jnp.int32)
+            logits, kv = self._chunk_jit(self.params, _read_lane_kv(self.cache, lane),
+                                         toks, poss)
+            self.cache = _write_lane_kv(self.cache, kv, lane)
+        else:
+            logits, self.cache = self._chunk_jit(self.params, self.cache, toks, poss)
+        jax.block_until_ready(logits[0, 0])
+
+    def _sample_prefilled(self, group: list, logits, c: int, width: int,
+                          single: bool) -> None:
+        """First token of every lane of ``group`` whose feed ends in call
+        ``c`` (``width`` positions a row; a single-lane call holds its lane
+        in row 0)."""
+        for lane, s, feed, start in group:
+            at = len(feed) - start - 1 - c * width
+            if not 0 <= at < width:
+                continue
+            row = logits[0 if single else lane, at]
             if self.cfg.nan_guard and not bool(jnp.all(jnp.isfinite(row))):
                 if self._nan_attr_tick != self.tick:
                     self._nan_attr_tick = self.tick
@@ -1010,13 +1108,16 @@ class ServeEngine:
             if reason:
                 self._finalize(lane, s, reason)
 
-    def _block_table_args(self) -> tuple:
+    def _block_table_args(self, lanes: Optional[list] = None):
         """The paged steps' block-table argument, copied to the device (no
-        argument for dense KV)."""
+        argument for dense KV): every lane's row, or with ``lanes`` a list of
+        one-row tables, one for each lane."""
         if not self.paged:
-            return ()
+            return () if lanes is None else [()] * len(lanes)
         with tracing.span(tracing.UPLOAD):
-            return (jnp.asarray(self._bt),)
+            if lanes is None:
+                return (jnp.asarray(self._bt),)
+            return [(jnp.asarray(self._bt[i:i + 1]),) for i in lanes]
 
     # ------------------------------------------------------------------
     def step(self) -> None:

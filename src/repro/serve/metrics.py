@@ -42,6 +42,7 @@ class EngineMetrics:
         self.prefill_s: list = []  # per prefill flush (all chunks)
         self.prefill_tokens = 0  # prompt tokens prefilled
         self.prefill_slots = 0  # lane-positions the prefill calls computed, pads included
+        self.prefill_lane_calls = 0  # prefill calls in the single-lane shape
         self.ttft_s: list = []  # per finished request
         self.token_latency_s: list = []  # inter-token gaps, pooled
         self.generated_tokens = 0
@@ -71,12 +72,15 @@ class EngineMetrics:
         self.decode_s.append(decode_seconds)
         self.occupancy.append(n_active)
 
-    def record_prefill(self, seconds: float, n_tokens: int, n_slots: int) -> None:
+    def record_prefill(self, seconds: float, n_tokens: int, n_slots: int,
+                       lane_calls: int = 0) -> None:
         """One flush: ``n_tokens`` real positions fed, ``n_slots`` computed
-        (lanes x ``prefill_chunk`` per chunk call)."""
+        (``n_slots`` x ``prefill_chunk`` per call, either shape), of which
+        ``lane_calls`` calls took the single-lane shape."""
         self.prefill_s.append(seconds)
         self.prefill_tokens += n_tokens
         self.prefill_slots += n_slots
+        self.prefill_lane_calls += lane_calls
 
     def record_pages(self, pages_in_use: int) -> None:
         self.pages_used.append(pages_in_use)

@@ -21,7 +21,7 @@ import jax
 TICK = "serve.tick"  # the whole of step(); metadata: tick
 ADMIT = "serve.admit"  # cancellations, deadlines, admission (page plans, CoW copies)
 PREFILL = "serve.prefill"  # one prefill flush; metadata: rids, tokens (positions fed)
-PREFILL_CHUNK = "serve.prefill.chunk"  # one chunk's input slice and dispatch
+PREFILL_CHUNK = "serve.prefill.chunk"  # one call's input slice and dispatch; metadata: lanes
 PREFILL_SAMPLE = "serve.prefill.sample"  # one chunk's ending lanes: NaN check, sampler
 GROW = "serve.grow"  # paged: every lane's page growth (and preemptions)
 UPLOAD = "serve.upload"  # paged: the block table copied to the device

@@ -63,11 +63,12 @@ def sample(finished: list, seed: int, n_slots: int) -> list:
     return [done[i] for i in picked]
 
 
-def compare(shapes, seed: int, arch, finished: list, length: int, n_slots: int,
+def compare(shapes, seed: int, family, arch, finished: list, length: int, n_slots: int,
             control: bool = False, log=print) -> dict:
-    """Readings of the sample: the widest served gap, and with ``control``
-    the widest gap of the fp8 control's choices on the same tokens.  Every
-    sequence is padded to ``length`` (the engine's ``max_len``)."""
+    """Readings of the sample through ``family``'s reference layer: the
+    widest served gap, and with ``control`` the widest gap of the fp8
+    control's choices on the same tokens.  Every sequence is padded to
+    ``length`` (the engine's ``max_len``)."""
     t = time.perf_counter()
     picked = sample(finished, seed, n_slots)
     out = {"incomplete": sum(1 for _, o, want, why, _ in finished
@@ -77,13 +78,13 @@ def compare(shapes, seed: int, arch, finished: list, length: int, n_slots: int,
         out["max_logit_gap"] = float("inf")
         return out
     seqs = [p + o[:-1] for p, o, _ in picked]
-    head = reference.head_weights(shapes, seed)
-    hs = reference.hidden(shapes, seed, arch, seqs, length)
+    head = reference.head_weights(shapes, seed, family)
+    hs = reference.hidden(shapes, seed, family, arch, seqs, length)
     out["max_logit_gap"] = max(
         float(reference.served_gaps(h, head, p, o, arch.vocab).max())
         for h, (p, o, _) in zip(hs, picked))
     if control:
-        hc = reference.hidden(shapes, seed, arch, seqs, length, fp8=True)
+        hc = reference.hidden(shapes, seed, family, arch, seqs, length, fp8=True)
         out["control_gap"] = max(
             float(reference.choice_gaps(h, c, head, p, o, arch.vocab).max())
             for h, c, (p, o, _) in zip(hs, hc, picked))

@@ -17,7 +17,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-from chipbench import check, counts, reference, traffic, weights
+from chipbench import check, counts, traffic, weights
 
 # requests that never got a first token are waited for this long after the close
 FIRST_TOKEN_WAIT_S = 60.0
@@ -73,7 +73,7 @@ class Call:
 class Run:
     """Everything a metric reader may read."""
     cell: object  # spec.Cell
-    shape: counts.Shape
+    shape: object  # the family's Shape
     peaks: dict
     n_slots: int
     t0: float = 0.0
@@ -122,33 +122,6 @@ class Run:
         return 100.0 * (least / len(calls)) / (self.trace["program_s"][kind] / runs)
 
 
-def model_config(conf: dict):
-    """The program's ModelConfig for a configuration file: its architecture
-    from the registry, every size from the file."""
-    from repro.configs import get_config
-
-    return get_config(conf["arch"]).replace(
-        n_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"],
-        vocab_size=conf["vocab_size"],
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        qkv_bias=bool(conf["attention_bias"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        dtype=conf["torch_dtype"],
-        param_dtype=conf["torch_dtype"],
-    )
-
-
-def reference_arch(cfg) -> reference.Arch:
-    return reference.Arch(cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                          cfg.head_dim, cfg.vocab_size, cfg.rope_theta, cfg.norm_eps)
-
-
 class Harness:
     """One cell on one chip: ``setup``, ``window``, ``wait_first_tokens``,
     ``free``, then ``correctness``."""
@@ -162,10 +135,11 @@ class Harness:
         from repro.models import build_model
         from repro.serve import EngineConfig, ServeEngine
 
-        self.cfg = model_config(self.cell.config)
+        family = self.cell.family
+        self.cfg = family.program_config(self.cell.config)
         self.model = build_model(self.cfg)
         self.shapes = jax.eval_shape(self.model.init, jax.random.key(0))
-        params = weights.make(self.shapes, self.seed, self.cfg.n_layers)
+        params = weights.make(self.shapes, self.seed, family.leaf_std)
         self.engine = ServeEngine(self.model, params,
                                   EngineConfig(**self.cell.config["engine"], guard="off"))
         del params
@@ -189,7 +163,7 @@ class Harness:
         self.backlog = int(self.mix.get("backlog_per_slot", 0) * self.engine.cfg.n_slots)
         self.lanes = {}  # request id -> lane
         jax.block_until_ready(self.engine.cache)
-        self.run = Run(self.cell, counts.Shape.of(self.cfg), self.peaks,
+        self.run = Run(self.cell, family.Shape.of(self.cfg), self.peaks,
                        self.engine.cfg.n_slots)
 
     # -- the window ---------------------------------------------------------
@@ -304,6 +278,7 @@ class Harness:
 
     def correctness(self, control: bool = False) -> dict:
         """Readings of a sample of finished requests against the reference."""
-        return check.compare(self.shapes, self.seed, reference_arch(self.cfg),
+        family = self.cell.family
+        return check.compare(self.shapes, self.seed, family, family.arch(self.cfg),
                              self.finished, self.max_len, self.n_slots, control=control,
                              log=self.log)

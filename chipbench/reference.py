@@ -1,17 +1,20 @@
-"""Plain float32 reference of the dense GQA decoder, and its fp8 control.
+"""Plain float32 reference of a served decoder, and its fp8 control: the
+parts every family shares.
 
-Written from the published architecture (Qwen2 / Llama family), not from the
-program: token embedding, then per layer RMSNorm -> q, k, v projections
-(with bias where the configuration has it) -> rotary embedding (rotate-half,
-inverse frequencies theta^(-2i/hd)) -> causal softmax attention where query
-head h reads KV head h // (H/K) -> output projection -> residual -> RMSNorm
--> SwiGLU (silu(x Wg) * (x Wu)) Wd -> residual; final RMSNorm; untied head.
-All in float32 under ``jax.default_matmul_precision("highest")``.
+A family (``chipbench/families/<name>.py``) brings its own decoder layer,
+``layer(x, w, arch, fp8)``, written from its published architecture and
+not from the program, and its ``arch(cfg)``: a hashable description with at
+least ``n_layers``, ``vocab`` and ``eps``.  Shared here: the token
+embedding, the layer loop, the final RMSNorm and the untied head; the
+building blocks a layer uses (``linear``, ``rmsnorm``, ``rope``, causal GQA
+``attention``, ``fp8_round``); and the gaps the check reads.  All in
+float32 under ``jax.default_matmul_precision("highest")``.  A new family
+adds its layer as a new file and edits nothing here.
 
 It imports nothing of ``src/`` and takes nothing the program made: every
-layer's weights are drawn again from the seed by ``weights.py``, one layer at
-a time, so the reference fits beside nothing else on the chip once the
-served model is freed.
+layer's weights are drawn again from the seed by ``weights.py`` with the
+family's rule, one layer at a time, so the reference fits beside nothing
+else on the chip once the served model is freed.
 
 The control is the same forward with every linear layer computed in fp8
 (e4m3, weights scaled per tensor, activations per token): the precision a
@@ -19,7 +22,6 @@ later change would be tempted to serve in.  It has to fail the comparison.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
 from functools import partial
 
 import jax
@@ -33,37 +35,25 @@ FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
 
 
-@dataclass(frozen=True)
-class Arch:
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    vocab: int
-    rope_theta: float
-    eps: float
-
-
-def _fp8(x, axis):
+def fp8_round(x, axis):
     """Round to e4m3 with an amax scale over ``axis`` (None: whole tensor)."""
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
     s = jnp.maximum(amax, 1e-30) / FP8_MAX
     return (x / s).astype(FP8).astype(jnp.float32) * s
 
 
-def _linear(x, w, fp8: bool):
+def linear(x, w, fp8: bool):
     """x (..., d_in) @ w (d_in, ...)."""
     if fp8:
-        x, w = _fp8(x, -1), _fp8(w, None)
+        x, w = fp8_round(x, -1), fp8_round(w, None)
     return jnp.tensordot(x, w, axes=1)
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x (T, heads, hd) at positions 0..T-1."""
     t, _, hd = x.shape
     inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -73,7 +63,7 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(q, k, v):
+def attention(q, k, v):
     """Causal GQA attention.  q (T, H, hd), k/v (T, K, hd) -> (T, H, hd)."""
     t, h, hd = q.shape
     rep = h // k.shape[1]
@@ -89,34 +79,15 @@ def _attention(q, k, v):
     return jnp.concatenate(outs, 0)
 
 
-@partial(jax.jit, static_argnames=("arch", "fp8"))
-def _layer(x, w, arch: Arch, fp8: bool):
-    """One decoder layer on x (T, d) float32; ``w`` holds float32 leaves."""
-    with jax.default_matmul_precision("highest"):
-        a = w["attn"]
-        h = _rmsnorm(x, w["attn_norm"]["scale"], arch.eps)
-        q = _linear(h, a["wq"], fp8)
-        k = _linear(h, a["wk"], fp8)
-        v = _linear(h, a["wv"], fp8)
-        if "bq" in a:
-            q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-        o = _attention(_rope(q, arch.rope_theta), _rope(k, arch.rope_theta), v)
-        x = x + _linear(o.reshape(o.shape[0], -1), a["wo"].reshape(-1, arch.d_model), fp8)
-        m = w["mlp"]
-        h = _rmsnorm(x, w["mlp_norm"]["scale"], arch.eps)
-        g = jax.nn.silu(_linear(h, m["wi_gate"], fp8)) * _linear(h, m["wi_up"], fp8)
-        return x + _linear(g, m["wo"], fp8)
-
-
 @partial(jax.jit, static_argnames=("arch",))
-def _final(x, scale, arch: Arch):
-    return _rmsnorm(x, scale, arch.eps)
+def _final(x, scale, arch):
+    return rmsnorm(x, scale, arch.eps)
 
 
 @partial(jax.jit, static_argnames=("vocab", "fp8"))
 def _logits(h, head, vocab: int, fp8: bool):
     with jax.default_matmul_precision("highest"):
-        return _linear(h, head, fp8)[:, :vocab]
+        return linear(h, head, fp8)[:, :vocab]
 
 
 @partial(jax.jit, static_argnames=("vocab",))
@@ -142,27 +113,29 @@ def _padded(toks, length: int) -> np.ndarray:
     return out
 
 
-def hidden(shapes, seed: int, arch: Arch, seqs: list, length: int,
+def hidden(shapes, seed: int, family, arch, seqs: list, length: int,
            fp8: bool = False) -> list:
     """Final normalised hidden states, float32 on the device, of each token
     list in ``seqs`` padded to ``length`` (causal: the pads change nothing
-    before them; one length, so one program per cell).  Layer-major: each
-    layer's weights are drawn once and applied to every sequence."""
-    embed = weights.top(shapes, seed, "embed")
+    before them; one length, so one program per cell), through ``family``'s
+    layer.  Layer-major: each layer's weights are drawn once and applied to
+    every sequence."""
+    rule = family.leaf_std
+    embed = weights.top(shapes, seed, "embed", rule)
     xs = [embed[jnp.asarray(_padded(t, length))].astype(jnp.float32) for t in seqs]
     del embed
-    layer_fn = jax.jit(lambda k, i: _f32(weights.layer_from_key(shapes, k, i)))
+    layer_fn = jax.jit(lambda k, i: _f32(weights.layer_from_key(shapes, k, i, rule)))
     key = weights.key_for(seed)
     for i in range(arch.n_layers):
         w = layer_fn(key, i)
-        xs = [_layer(x, w, arch, fp8) for x in xs]
+        xs = [family.layer(x, w, arch, fp8) for x in xs]
         del w
-    scale = weights.top(shapes, seed, "final_norm").astype(jnp.float32)
+    scale = weights.top(shapes, seed, "final_norm", rule).astype(jnp.float32)
     return [_final(x, scale, arch) for x in xs]
 
 
-def head_weights(shapes, seed: int):
-    return weights.top(shapes, seed, "lm_head").astype(jnp.float32)
+def head_weights(shapes, seed: int, family):
+    return weights.top(shapes, seed, "lm_head", family.leaf_std).astype(jnp.float32)
 
 
 def served_gaps(h, head, prompt: list, out: list, vocab: int) -> np.ndarray:

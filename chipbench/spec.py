@@ -2,22 +2,31 @@
 
 Nothing here knows a cell, a configuration, a mix or a metric by name:
 
-- a configuration is the file its ``configs`` entry names, with every size
-  and the engine's settings (``EngineConfig``: lanes, ``max_len``, chunk,
-  KV layout);
+- a configuration is the file its ``configs`` entry names, with every size,
+  the engine's settings (``EngineConfig``: lanes, ``max_len``, chunk, KV
+  layout) and its ``family``;
+- a family is ``chipbench/families/<family>.py``, which brings what the
+  benchmark knows of one architecture: ``program_config(conf)`` (the
+  program's ModelConfig), ``leaf_std(path, shape)`` (its weight rule, for
+  ``weights.py``), ``arch(cfg)`` and ``layer(x, w, arch, fp8)`` (its float32
+  reference layer and fp8 control, for ``reference.py``), and ``Shape``
+  (its counts, for ``counts.py``); the rest of the harness is shared;
 - a traffic mix is ``chipbench/traffic/<traffic>.json``;
 - a metric is ``chipbench/metrics/<name>.py``, whose ``read(run)`` returns
   the number or None when the run holds nothing to read.
 
-A later change adds a cell, a configuration, a mix or a metric by adding
-files and entries, and edits no file that is here.
+A later change adds a cell, a configuration, a family, a mix or a metric by
+adding files and entries, and edits no file that is here.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
+from functools import lru_cache
 from pathlib import Path
+from types import ModuleType
 from typing import Callable
 
 
@@ -26,6 +35,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    family: ModuleType  # chipbench/families/<config's family>.py
     traffic: dict
     end_to_end: tuple  # BENCHMARK.json metric entries this cell reports
     per_layer: tuple
@@ -47,28 +57,54 @@ def load_cell(root: Path, name: str) -> Cell:
         raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = json.loads((root / conf["file"]).read_text())
     e2e = tuple(m for m in bench["end_to_end"] if _applies(m, name))
     names = {m["name"] for m in e2e}
     per_layer = tuple(m for m in bench["per_layer"] if _applies(m, name, names))
     return Cell(
         name=name,
         chips=int(w["chips"]),
-        config=json.loads((root / conf["file"]).read_text()),
+        config=config,
+        family=family(root, config, conf["file"]),
         traffic=json.loads((root / "chipbench" / "traffic" / f"{w['traffic']}.json").read_text()),
         end_to_end=e2e,
         per_layer=per_layer,
     )
 
 
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # where dataclasses look up the module's names
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(root: Path, metric: str) -> Callable:
     """``read`` of ``chipbench/metrics/<metric>.py``."""
     path = root / "chipbench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{metric}", path)
-    if spec is None or not path.is_file():
+    if not path.is_file():
         raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, f"chipbench_metric_{metric}").read
+
+
+def family(root: Path, config: dict, where: str) -> ModuleType:
+    """The family module that the configuration ``config`` (read from
+    ``where``) names.  Never a default: a configuration without ``family``,
+    or one whose family has no file, is refused with the file to add."""
+    if "family" not in config:
+        raise KeyError(f"{where} names no family: add \"family\": \"<name>\" and "
+                       "chipbench/families/<name>.py")
+    path = root / "chipbench" / "families" / f"{config['family']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{where} names family {config['family']!r}: add {path}")
+    return _family(path.resolve())
+
+
+@lru_cache(maxsize=None)
+def _family(path: Path) -> ModuleType:
+    """Each file loaded once a process, so its jitted layer compiles once."""
+    return _load(path, f"chipbench_family_{path.stem}")
 
 
 def peaks(root: Path, device_kind: str) -> dict:

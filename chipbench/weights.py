@@ -8,9 +8,11 @@ to the served dtype.  The same (seed, leaf, layer) gives the same bits on any
 device and in any program, so ``reference.py`` regenerates one layer at a time
 what the served model holds, without taking anything from the program.
 
-Scales follow the usual initialisation: a matrix draws with standard
-deviation 1/sqrt(fan_in); the embedding with 1; norm scales are 1 +- 0.25;
-biases have standard deviation about 0.1.
+Shared by every family: the keys, the draw and the layer-by-layer build.
+The scale of each leaf is the family's: its ``leaf_std(path, shape)``
+(``chipbench/families/<name>.py``) gives the leaf's kind ('matrix', 'scale':
+drawn about 1, or 'bias') and standard deviation, and is passed here as
+``rule``.  A family with other leaves brings its rule as a new file.
 """
 from __future__ import annotations
 
@@ -25,10 +27,6 @@ import numpy as np
 # uniform int16 has standard deviation 2**15 / sqrt(3)
 _INT16_STD = 2.0 ** 15 / math.sqrt(3.0)
 
-# leaf name -> axis (of the per-layer shape) whose size is the fan-in, or a kind
-_FAN_IN = {"wq": 0, "wk": 0, "wv": 0, "wi_gate": 0, "wi_up": 0, "lm_head": 0}
-_FAN_IN_PRODUCT = {"wo"}  # attention (H, hd, d) or mlp (f, d): all but the last axis
-
 
 def key_for(seed: int):
     """A JAX key from any non-negative seed (JAX keeps 32 bits of an int)."""
@@ -41,25 +39,10 @@ def _scale_exp(std: float) -> int:
     return round(math.log2(std / _INT16_STD))
 
 
-def leaf_std(path: tuple, shape: tuple) -> tuple:
-    """(kind, std) of one per-layer leaf; kind is 'matrix', 'scale' or 'bias'."""
-    name = path[-1]
-    if name == "scale":
-        return "scale", 0.25 / math.sqrt(3.0)
-    if name in ("bq", "bk", "bv"):
-        return "bias", 0.1
-    if name == "embed":
-        return "matrix", 1.0
-    if name in _FAN_IN:
-        return "matrix", 1.0 / math.sqrt(shape[_FAN_IN[name]])
-    if name in _FAN_IN_PRODUCT:
-        return "matrix", 1.0 / math.sqrt(int(np.prod(shape[:-1])))
-    raise KeyError(f"no weight rule for parameter {'/'.join(path)}")
-
-
-def draw(key, path: tuple, shape: tuple, dtype):
-    """One per-layer leaf from ``key`` (already folded with leaf and layer)."""
-    kind, std = leaf_std(path, shape)
+def draw(key, path: tuple, shape: tuple, dtype, rule):
+    """One per-layer leaf from ``key`` (already folded with leaf and layer),
+    scaled by the family's ``rule``."""
+    kind, std = rule(path, shape)
     bits = jax.random.bits(key, shape, jnp.uint16)
     ints = jax.lax.bitcast_convert_type(bits, jnp.int16).astype(jnp.float32)
     x = ints * np.float32(2.0 ** _scale_exp(std))  # exact: a power of two
@@ -78,26 +61,26 @@ def _paths(tree) -> list:
     return [(tuple(p.key for p in path), leaf) for path, leaf in flat]
 
 
-def _build(shapes, n_layers: int, key):
+def _build(shapes, rule, key):
     leaves = []
     for path, s in _paths(shapes):
-        if path[0] == "layers":
-            leaves.append(jnp.stack([draw(_leaf_key(key, path, i), path, s.shape[1:], s.dtype)
-                                     for i in range(n_layers)]))
+        if path[0] == "layers":  # the layer axis first
+            leaves.append(jnp.stack([draw(_leaf_key(key, path, i), path, s.shape[1:], s.dtype,
+                                          rule) for i in range(s.shape[0])]))
         else:
-            leaves.append(draw(_leaf_key(key, path, 0), path, s.shape, s.dtype))
+            leaves.append(draw(_leaf_key(key, path, 0), path, s.shape, s.dtype, rule))
     return jax.tree.unflatten(jax.tree.structure(shapes), leaves)
 
 
-def make(shapes, seed: int, n_layers: int):
+def make(shapes, seed: int, rule):
     """The whole parameter tree for ``shapes`` (``jax.eval_shape`` of the
     program's init; leaves under ``layers`` carry the layer axis first), as
     one compiled call on the default device.  The key is an argument, so
     every seed reuses the one program."""
-    return jax.jit(partial(_build, shapes, n_layers))(key_for(seed))
+    return jax.jit(partial(_build, shapes, rule))(key_for(seed))
 
 
-def layer_from_key(shapes, key, index) -> dict:
+def layer_from_key(shapes, key, index, rule) -> dict:
     """One layer's leaves (no layer axis), as the served model holds them;
     ``index`` may be traced."""
     out = {}
@@ -107,14 +90,14 @@ def layer_from_key(shapes, key, index) -> dict:
         node = out
         for p in path[1:-1]:
             node = node.setdefault(p, {})
-        node[path[-1]] = draw(_leaf_key(key, path, index), path, s.shape[1:], s.dtype)
+        node[path[-1]] = draw(_leaf_key(key, path, index), path, s.shape[1:], s.dtype, rule)
     return out
 
 
-def top(shapes, seed: int, name: str):
-    """A leaf outside the layer stack (``embed``, ``lm_head``, ``final_norm``)."""
+def top(shapes, seed: int, name: str, rule):
+    """A leaf outside the layer stack, by its name at the top of the tree."""
     for path, s in _paths(shapes):
         if path[0] == name:  # jitted: no full-size float32 temporaries
-            return jax.jit(lambda k: draw(_leaf_key(k, path, 0), path, s.shape, s.dtype))(
-                key_for(seed))
+            return jax.jit(lambda k: draw(_leaf_key(k, path, 0), path, s.shape, s.dtype,
+                                          rule))(key_for(seed))
     raise KeyError(name)

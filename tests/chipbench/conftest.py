@@ -1,5 +1,6 @@
-"""A tiny benchmark tree for CPU tests: the real metrics, peaks and schema,
-with cells of a two-layer model small enough to serve on the CPU."""
+"""A tiny benchmark tree for CPU tests: the real metrics, families, peaks and
+schema, with cells of a two-layer model small enough to serve on the CPU,
+and the test-only families of ``data/families``."""
 import json
 import shutil
 import sys
@@ -34,7 +35,10 @@ def tiny_bench() -> dict:
 def build_root(path: Path) -> Path:
     """A checkout-like tree under ``path`` for the tiny cells."""
     cb = path / "chipbench"
-    shutil.copytree(REPO / "chipbench" / "metrics", cb / "metrics")
+    skip = shutil.ignore_patterns("__pycache__")
+    for sub in ("metrics", "families"):
+        shutil.copytree(REPO / "chipbench" / sub, cb / sub, ignore=skip)
+    shutil.copytree(DATA / "families", cb / "families", ignore=skip, dirs_exist_ok=True)
     shutil.copy(REPO / "chipbench" / "peaks.json", cb / "peaks.json")
     for sub in ("configs", "traffic", "limits"):
         shutil.copytree(DATA / sub, cb / sub)

@@ -1,32 +1,38 @@
-"""FLOP and byte counts from shapes, against numbers worked by hand."""
+"""FLOP and byte counts from shapes, through each configuration's family,
+against numbers worked by hand."""
 import json
 
 import pytest
 from conftest import REPO
 
-from chipbench import counts
+from chipbench import counts, spec
 
 
-def shape(name: str) -> counts.Shape:
+def shape(name: str):
     c = json.loads((REPO / "chipbench" / "configs" / f"{name}.json").read_text())
-    return counts.Shape(c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"],
-                        c["num_key_value_heads"], c["head_dim"], c["intermediate_size"],
-                        c["vocab_size"], c["attention_bias"])
+    family = spec.family(REPO, c, name)
+    return family.Shape.of(family.program_config(c))
 
 
-@pytest.mark.parametrize("name,layer,params,kv", [
+# the last two: a 512-token prefill call from position 0 whose last token's
+# logits are used, one lane reaching 512 positions
+@pytest.mark.parametrize("name,layer,params,kv,flops_512,bytes_512", [
     # 2*5120*40*128 + 2*5120*8*128 + 3*5120*13824 + (40+16)*128 bias + 2*5120 norms
-    ("qwen2.5-14b", 275_268_608, 4_860_363_776, 49_152),
+    ("qwen2.5-14b", 275_268_608, 4_860_363_776, 49_152, 3_416_119_050_240, 8_219_166_720),
     # 2*7168*56*128 + 2*7168*8*128 + 3*7168*20480 + 2*7168 norms
-    ("yi-34b", 557_856_768, 5_380_365_312, 32_768),
+    ("yi-34b", 557_856_768, 5_380_365_312, 32_768, 4_600_886_198_272, 9_884_121_088),
 ])
-def test_parameters_and_kv(name, layer, params, kv):
+def test_parameters_and_kv(name, layer, params, kv, flops_512, bytes_512):
     s = shape(name)
     assert s.layer_params == layer
     assert s.params == params
     assert s.kv_bytes_per_token == kv
     if name == "qwen2.5-14b":
         assert 2 * s.params == 9_720_727_552  # parameter bytes measured on the chip
+        assert shape("qwen2.5-14b-paged") == s
+    toks = [(p, p == 511) for p in range(512)]
+    assert s.call_flops(toks) == flops_512
+    assert s.call_bytes(toks, [512]) == bytes_512
 
 
 def test_token_flops_by_hand():

@@ -25,7 +25,7 @@ def test_compiled_steps_carry_the_names_the_trace_reduction_finds(conf):
     """The engine as the harness builds it: a renamed model function would
     leave ``decode_roofline``/``prefill_roofline`` with nothing to read."""
     config = json.loads((DATA / "configs" / f"{conf}.json").read_text())
-    model = build_model(harness.model_config(config))
+    model = build_model(spec.family(REPO, config, conf).program_config(config))
     eng = ServeEngine(model, model.init(jax.random.key(0)),
                       EngineConfig(**config["engine"], guard="off"))
     bt = (jnp.asarray(eng._bt),) if eng.paged else ()

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from conftest import REPO
 
-from chipbench import counts, harness, spec, stats
+from chipbench import harness, spec, stats
 
 
 def session(tokens, admitted=None):
@@ -17,7 +17,7 @@ def session(tokens, admitted=None):
 
 
 def make_run():
-    shape = counts.Shape(1, 8, 2, 1, 4, 16, 32, False)
+    shape = spec.family(REPO, {"family": "dense_gqa"}, "test").Shape(1, 8, 2, 1, 4, 16, 32, False)
     run = harness.Run(cell=None, shape=shape, peaks={}, n_slots=4, t0=100.0,
                       t_close=110.0, t_end=111.0)
     # due 100, first token 100.5, tokens every 0.1 s until 101.0
